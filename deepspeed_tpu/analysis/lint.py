@@ -6,7 +6,7 @@ the moment a patch re-violates one (``tests/unit/test_lint.py``):
 R1 **raw shard_map** — ``jax.shard_map`` / ``jax.experimental.shard_map``
    moved twice across jax releases (``check_rep`` -> ``check_vma``,
    ``auto`` -> ``axis_names``); every module must go through
-   ``utils/shard_map_compat`` so the version probe lives in one place.
+   ``utils/shard_map_compat`` so the next move is a one-file edit.
 R2 **host syncs in default-on paths** — ``block_until_ready`` /
    ``jax.device_get`` in ``runtime/engine.py`` or ``telemetry/`` serialize
    the async dispatch pipeline for every user.  Deliberate sites (the
@@ -42,7 +42,7 @@ import os
 import tokenize
 from typing import Dict, Iterable, List, Optional, Set, Tuple
 
-#: modules allowed to touch raw shard_map (the version shim itself)
+#: modules allowed to touch raw shard_map (the shared wrapper itself)
 SHARD_MAP_EXEMPT = ("utils/shard_map_compat.py",)
 #: path prefixes where host syncs are forbidden unless annotated: the
 #: engine hot path, the (default-off but attach-everywhere) telemetry,
@@ -119,8 +119,8 @@ def _lint_shard_map(tree: ast.AST, rel: str,
                     mod == "jax.experimental" and "shard_map" in names):
                 findings.append(LintFinding(
                     "raw-shard-map", rel, node.lineno,
-                    "import shard_map via utils/shard_map_compat (the "
-                    "check_rep/check_vma version probe lives there)"))
+                    "import shard_map via utils/shard_map_compat (the one "
+                    "module that spells the jax API)"))
         elif isinstance(node, ast.Attribute):
             chain = _call_name_chain(node)
             if chain[-1:] == ["shard_map"] and chain[:1] == ["jax"]:

@@ -211,8 +211,15 @@ class InferenceEngineV2:
             return (f"kv_cache_dtype={self.config.kv_cache_dtype} "
                     "storage-cast pools dequantize on the gather path")
         if jax.default_backend() == "tpu" and self.cfg.head_dim % 128:
+            # the kernel compiles and agrees with the einsum path at
+            # head_dim 64 (tests/unit/test_tpu_hardware.py), but XLA:TPU
+            # keeps a pool whose rows are narrower than the 128 lanes in a
+            # slot-minor layout, and the kernel's row-major operand then
+            # makes it copy BOTH whole pools (padded to the lane width) on
+            # every call: 4x the pool's bytes of temporaries per step
             return (f"head_dim {self.cfg.head_dim} is not a 128-lane "
-                    "multiple on this TPU")
+                    "multiple: the resident-pool kernel would make XLA "
+                    "relayout the whole pool on every call")
         return None
 
     def _resolve_decode_attn(self, kv_dtype, dtype):
@@ -231,15 +238,19 @@ class InferenceEngineV2:
         elif c.attn_backend != "auto":
             want, source = c.attn_backend, "config"
         if want == "auto":
-            try:
-                from ...comm.planner import get_planner, planner_active
+            from ...comm.planner import get_planner, planner_active
 
-                if planner_active():
+            if planner_active():
+                try:
                     d = get_planner().resolve(self._decode_attn_site(kv_dtype))
+                except Exception as e:  # noqa: BLE001 - a planner fault must
+                    # not block engine bring-up, but it is said, not hidden
+                    _warn_decode_once(
+                        f"decode_attn planner resolve failed ({e!r}) — "
+                        "using the platform heuristic")
+                else:
                     if d.impl in ("pallas", "einsum"):
                         want, source = d.impl, "planner"
-            except Exception:  # planning must never block engine bring-up
-                pass
         if want == "auto":
             want = "pallas" if jax.default_backend() == "tpu" else "einsum"
             source = "heuristic"
@@ -674,9 +685,7 @@ class InferenceEngineV2:
 
     def decode_stream(self, total_steps: int) -> Dict[int, List[int]]:
         """Fused decode of ``total_steps`` tokens in ONE dispatch + ONE host
-        sync (``model.decode_loop`` scans the whole run on device). On
-        remote-attached TPUs each dispatch costs a round-trip, so batch
-        generation wants exactly one.
+        sync (``model.decode_loop`` scans the whole run on device).
 
         Generates ``min(total_steps, min remaining)`` tokens, rounded UP to a
         ``decode_chunk`` multiple when KV capacity allows — ``n_steps`` is a

@@ -10,8 +10,9 @@ packed token buffer —
   every scheduled token, prompt chunk or decode, shares the same matmuls —
   this is the Dynamic SplitFuse property);
 * per-sequence grouping is a static-shape gather ``[S, Q]``;
-* KV pages are scattered/gathered with the trash-block convention (pad
-  writes land in block 0, never read);
+* KV pages are written a page at a time (``_kv_write``) and gathered with
+  the trash-block convention (a slot with nothing to write rewrites page 0,
+  never read);
 * paged attention = grouped-GQA einsum over gathered pages with an
   absolute-position mask.
 
@@ -68,14 +69,59 @@ def _kv_layer(pool, i):
     return pool[i]
 
 
-def _kv_write(pool, i, tgt_block, tgt_slot, vals):
-    """Scatter new KV rows ``vals`` [T', Hk, D] into layer ``i``'s pages."""
+def _write_plan(block_table, pos, valid, bs: int):
+    """Page-granular write plan for one step's new KV rows.
+
+    Each sequence's new rows sit at contiguous positions (the SplitFuse
+    packing invariant; the fused-decode window is contiguous by
+    construction), so ``Q`` rows touch at most ``P = ceil((Q-1)/bs) + 1``
+    consecutive logical pages, starting at the page of the first row.
+    Returns ``(phys [S, P], row [S, Q])``: those pages' physical ids (the
+    trash page 0 where the sequence writes nothing or its table ends) and
+    each row's index inside the sequence's ``P * bs``-row span (``P * bs``,
+    out of range and so dropped, for invalid rows)."""
+    Q = pos.shape[1]
+    B = block_table.shape[1]
+    P = -(-(Q - 1) // bs) + 1
+    writes = jnp.any(valid, axis=1)                                 # [S]
+    first = jnp.min(jnp.where(valid, pos, jnp.iinfo(jnp.int32).max), axis=1)
+    lp0 = jnp.where(writes, first // bs, 0)
+    lp = lp0[:, None] + jnp.arange(P, dtype=jnp.int32)[None]        # [S, P]
+    phys = jnp.take_along_axis(block_table, jnp.minimum(lp, B - 1), axis=1)
+    phys = jnp.where(writes[:, None] & (lp < B), phys, 0)
+    row = jnp.where(valid, pos - lp0[:, None] * bs, P * bs)
+    return phys, row
+
+
+def _page_rmw(arr, i, phys, row, src):
+    """Read-modify-write layer ``i``'s pages ``phys`` of ``arr``
+    ``[L, N, Hk, bs, *tail]`` with rows ``src [S, Q, Hk, *tail]``.
+
+    Whole pages are gathered, patched while small, and scattered back along
+    the major (layer, page) axes, which XLA:TPU applies to the donated pool
+    in place. A row-granular scatter (``arr.at[i, blk, :, slot]``) indexes
+    inside the tiled minor dims instead, and the compiler then relayouts the
+    WHOLE pool around it — temporaries of several times the pool's size,
+    which no pool of a useful size survives."""
+    S, P = phys.shape
+    hk, bs = arr.shape[2], arr.shape[3]
+    tail = arr.shape[4:]
+    old = arr[i, phys]                                  # [S, P, Hk, bs, *tail]
+    tok = jnp.moveaxis(old, 3, 2).reshape(S, P * bs, hk, *tail)
+    tok = tok.at[jnp.arange(S)[:, None], row].set(src.astype(arr.dtype),
+                                                  mode="drop")
+    new = jnp.moveaxis(tok.reshape(S, P, bs, hk, *tail), 2, 3)
+    return arr.at[i, phys].set(new)
+
+
+def _kv_write(pool, i, phys, row, vals):
+    """Write new KV rows ``vals`` [S, Q, Hk, D] into layer ``i``'s pages
+    (``phys``/``row`` from :func:`_write_plan`)."""
     if isinstance(pool, tuple):
         q, s = pool
         qv, sv = quantize_rows(vals)
-        return (q.at[i, tgt_block, :, tgt_slot].set(qv),
-                s.at[i, tgt_block, :, tgt_slot].set(sv))
-    return pool.at[i, tgt_block, :, tgt_slot].set(vals.astype(pool.dtype))
+        return (_page_rmw(q, i, phys, row, qv), _page_rmw(s, i, phys, row, sv))
+    return _page_rmw(pool, i, phys, row, vals)
 
 
 def _gather_pages(pool, block_table, dtype):
@@ -301,11 +347,7 @@ def _ragged_hidden(params, cfg: TransformerConfig, kv_k, kv_v, tokens,
     q_valid = gather_idx < T                                        # [S, Q]
     safe_gather = jnp.minimum(gather_idx, T - 1)
     pos_g = jnp.where(q_valid, positions[safe_gather], 0)           # [S, Q]
-    # scatter targets for new KV: pad/invalid -> trash block 0, slot 0
-    blk_of_pos = jnp.take_along_axis(
-        block_table, (pos_g // bs).astype(jnp.int32), axis=1)       # [S, Q]
-    tgt_block = jnp.where(q_valid, blk_of_pos, 0).reshape(-1)
-    tgt_slot = jnp.where(q_valid, pos_g % bs, 0).reshape(-1)
+    w_phys, w_row = _write_plan(block_table, pos_g, q_valid, bs)
 
     h, hk, d = cfg.num_heads, cfg.kv_heads, cfg.head_dim
     rope_cs = (cos, sin) if cfg.position == "rope" else None
@@ -318,10 +360,8 @@ def _ragged_hidden(params, cfg: TransformerConfig, kv_k, kv_v, tokens,
         qg = jnp.concatenate([qt, jnp.zeros_like(qt[:1])])[gather_idx]
         kg = jnp.concatenate([kt, jnp.zeros_like(kt[:1])])[gather_idx]
         vg = jnp.concatenate([vt, jnp.zeros_like(vt[:1])])[gather_idx]
-        # write new kv into pages ([i, block, :, slot] — advanced indices
-        # around the head slice put the token axis first: values [T', Hk, D])
-        kv_k = _kv_write(kv_k, i, tgt_block, tgt_slot, kg.reshape(-1, hk, d))
-        kv_v = _kv_write(kv_v, i, tgt_block, tgt_slot, vg.reshape(-1, hk, d))
+        kv_k = _kv_write(kv_k, i, w_phys, w_row, kg)
+        kv_v = _kv_write(kv_v, i, w_phys, w_row, vg)
         if attn_impl == "pallas":
             if isinstance(kv_k, tuple):
                 raise ValueError(
@@ -463,9 +503,9 @@ def decode_loop(params, cfg: TransformerConfig, kv_k, kv_v, tokens0, pos0,
     """``n_steps`` fused decode iterations in ONE compiled program.
 
     The reference serving loop (and our ``step()``) round-trips host every
-    token: logits→sample→repack. On a remote-attached TPU that RTT dominates
-    decode latency, so this runs the whole forward→sample→KV-append loop as a
-    ``lax.scan`` on device and ships back only ``[S, n_steps]`` int32.
+    token: logits→sample→repack. This runs the whole
+    forward→sample→KV-append loop as a ``lax.scan`` on device instead and
+    ships back only ``[S, n_steps]`` int32.
 
     The KV pool is FROZEN during the scan. XLA (at least on this backend)
     copies a scanned carry on every iteration when it is updated by
@@ -595,17 +635,16 @@ def decode_loop(params, cfg: TransformerConfig, kv_k, kv_v, tokens0, pos0,
     (wk, wv, *_), toks = jax.lax.scan(
         body, (wk0, wv0, tokens0, pos0, key), jnp.arange(n_steps))
 
-    # one batched scatter of the whole window into the pool
-    tpos = pos0[:, None] + jnp.arange(W)[None]                      # [S, W]
-    blk = jnp.take_along_axis(block_table, (tpos // bs).astype(jnp.int32),
-                              axis=1)
-    blk = jnp.where(active[:, None], blk, 0).reshape(-1)
-    slot = jnp.where(active[:, None], tpos % bs, 0).reshape(-1)
-    wkt = wk.transpose(0, 2, 1, 3, 4).reshape(L, S * W, Hk, D)      # [L,S*W,..]
-    wvt = wv.transpose(0, 2, 1, 3, 4).reshape(L, S * W, Hk, D)
+    # one batched write of the whole window into the pool
+    tpos = pos0[:, None] + jnp.arange(W, dtype=jnp.int32)[None]     # [S, W]
+    w_phys, w_row = _write_plan(block_table, tpos,
+                                jnp.broadcast_to(active[:, None], tpos.shape),
+                                bs)
+    wkt = wk.transpose(0, 2, 1, 3, 4)                               # [L,S,W,..]
+    wvt = wv.transpose(0, 2, 1, 3, 4)
     for i in range(L):
-        kv_k = _kv_write(kv_k, i, blk, slot, wkt[i])
-        kv_v = _kv_write(kv_v, i, blk, slot, wvt[i])
+        kv_k = _kv_write(kv_k, i, w_phys, w_row, wkt[i])
+        kv_v = _kv_write(kv_v, i, w_phys, w_row, wvt[i])
     return toks.T, kv_k, kv_v                                       # [S, n_steps]
 
 

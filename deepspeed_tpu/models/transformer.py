@@ -239,6 +239,50 @@ def _warn_flash_fallback(reason: str) -> None:
         f"sites (one-time notice)")
 
 
+def resolve_attn_impl(impl: str, seq: int, *, biased: bool = False) -> str:
+    """``auto|xla|flash`` -> the attention this call site runs, in the
+    ``resolve_loss_impl`` order: an explicit model field wins, then the fleet
+    knob (``ops/fastpath.py``), then the heuristic — flash on a real
+    accelerator when the sequence tiles cleanly; the XLA reference (O(S^2)
+    logits) on CPU tests, odd lengths, and ``biased`` sites (ALiBi or a local
+    window: the flash kernel takes no additive bias)."""
+    if impl == "auto":
+        from ..ops.fastpath import fastpath
+
+        impl = fastpath("attn_impl")
+    if impl == "auto":
+        impl = ("flash" if jax.default_backend() != "cpu" and seq % 128 == 0
+                and not biased else "xla")
+    return impl
+
+
+def _flash_attention(q, k, v, *, causal: bool, scale):
+    """The Pallas flash kernel on ``[B, S, H, D]`` operands. GSPMD cannot
+    partition a Mosaic kernel (lowering refuses it on any multi-device
+    mesh), so there the call is wrapped in a shard_map: batch over the dp
+    axes, heads over tp, a dim that does not divide its axes replicated —
+    and under GQA q and kv heads shard together or not at all, since the
+    kernel maps query head ``h`` to kv head ``h // group`` by local index.
+    Inside an enclosing manual region (Ulysses, the SPMD pipeline body) the
+    operands are already per-shard and the kernel is called directly."""
+    from ..ops.pallas.flash_attention import flash_attention
+    from ..parallel.topology import TP_AXIS, get_topology
+    from ..utils.shard_map_compat import manual_axes, shard_map_nocheck
+
+    kernel = partial(flash_attention, causal=causal, sm_scale=scale)
+    topo = get_topology()
+    if topo.n_devices == 1 or manual_axes():
+        return kernel(q, k, v)
+    spec = sites.heads_sharded_act(topo.dp_axes, TP_AXIS)
+    q_spec = topo.filter_spec(spec, q.shape)
+    kv_spec = topo.filter_spec(spec, k.shape)
+    if q_spec[2] is None or kv_spec[2] is None:
+        q_spec = kv_spec = topo.filter_spec(
+            sites.heads_sharded_act(topo.dp_axes, None), q.shape)
+    return shard_map_nocheck(kernel, topo.mesh, (q_spec, kv_spec, kv_spec),
+                             q_spec)(q, k, v)
+
+
 def attention_core(q, k, v, *, causal: bool = True, impl: str = "auto",
                    positions_q=None, positions_kv=None, alibi=None,
                    scale=None, window=None, alibi_post_scale=False):
@@ -252,9 +296,7 @@ def attention_core(q, k, v, *, causal: bool = True, impl: str = "auto",
     ``window``: local attention — key j visible iff q_pos - j < window."""
     if impl == "flash":
         if alibi is None and window is None:
-            from ..ops.pallas.flash_attention import flash_attention
-
-            return flash_attention(q, k, v, causal=causal, sm_scale=scale)
+            return _flash_attention(q, k, v, causal=causal, scale=scale)
         _warn_flash_fallback("an ALiBi bias" if alibi is not None
                              else "a local attention window")
     b, sq, h, d = q.shape
@@ -571,19 +613,8 @@ class Attention(nn.Module):
                                        alibi_post_scale=cfg.alibi_post_scale)
             return o_proj(out), new_cache
 
-        impl = cfg.attn_impl
-        if impl == "auto":
-            from ..ops.fastpath import fastpath
-
-            impl = fastpath("attn_impl")
-        if impl == "auto":
-            # flash on real accelerators when the seq tiles cleanly; the XLA
-            # reference (O(S^2) logits) on CPU tests, odd shapes, and alibi/
-            # window (the flash kernel takes no additive bias). An explicit
-            # sm_scale no longer disqualifies — the kernel takes it.
-            seq = x.shape[1]
-            impl = "flash" if (jax.default_backend() != "cpu" and seq % 128 == 0
-                               and alibi is None and window is None) else "xla"
+        impl = resolve_attn_impl(cfg.attn_impl, x.shape[1],
+                                 biased=alibi is not None or window is not None)
 
         # Ulysses only in real execution: flax init traces tiny batches that
         # need not divide the mesh, and attention adds no params anyway.

@@ -37,8 +37,7 @@ def _constrain(x, spec, skip: bool = False):
         # inside shard_map (e.g. the SPMD pipeline body) the mesh axes are
         # manual: per-shard values carry no global sharding to constrain —
         # layout is already fixed by the enclosing in_specs
-        get_am = getattr(jax.sharding, "get_abstract_mesh", None)  # jax>=0.5
-        manual = getattr(get_am(), "manual_axes", ()) if get_am else ()
+        manual = jax.sharding.get_abstract_mesh().manual_axes
         axes_in_spec = {a for entry in spec if entry is not None
                         for a in (entry if isinstance(entry, tuple) else (entry,))}
         if axes_in_spec & set(manual):

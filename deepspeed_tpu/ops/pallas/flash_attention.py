@@ -24,15 +24,13 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .interpret import resolve_interpret
+
 # v5e-tuned: 512x512 tiles are ~4-5x faster than 128x128 (fewer grid steps,
 # full MXU occupancy); shapes that don't divide fall back via min(block, seq)
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 def _fit_blocks(seq: int, block: int) -> int:
@@ -136,6 +134,7 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
+        name="flash_attention_fwd",
         interpret=interpret,
     )(q, k, v)
     return o, L
@@ -279,6 +278,7 @@ def _flash_backward(res, g, causal, sm_scale, block_q, block_k, interpret):
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        name="flash_attention_bwd_dkdv",
         interpret=interpret,
     )(q, k, v, do.astype(q.dtype), L, delta)
     dk, dv = dkdv
@@ -305,6 +305,7 @@ def _flash_backward(res, g, causal, sm_scale, block_q, block_k, interpret):
         ],
         out_shape=[jax.ShapeDtypeStruct((b, h, sq, d), q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        name="flash_attention_bwd_dq",
         interpret=interpret,
     )(q, k, v, do.astype(q.dtype), L, delta)
     return dq, dk, dv
@@ -347,8 +348,7 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
     ``interpret=None`` auto-selects interpreter mode off-TPU so the same
     tests run on the CPU mesh (the parity-test pattern of reference
     ``tests/unit/ops``)."""
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(q.shape[-1])
     h, hk = q.shape[2], k.shape[2]

@@ -19,6 +19,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .interpret import resolve_interpret
+
 LANES = 128
 SUBLANES = 8
 TILE_ROWS = 512  # (512, 128) f32 tiles = 256 KB per operand in VMEM
@@ -55,8 +57,7 @@ def adam_update(g, m, v, p, lr, b1, b2, eps, weight_decay, adam_w_mode, bias_cor
                 step, interpret=None):
     """One fused Adam update on a single tensor shard. All math fp32.
     Returns ``(update, new_m, new_v)`` shaped like the input."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = resolve_interpret(interpret)
     shape = g.shape
     n = int(np.prod(shape)) if shape else 1
     cols = LANES

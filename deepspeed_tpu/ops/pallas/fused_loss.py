@@ -37,6 +37,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .interpret import resolve_interpret
+
 __all__ = ["fused_vocab_nll", "fused_loss_ready"]
 
 # v5e-sized defaults: a 256x512 logits tile keeps the MXU busy while
@@ -46,10 +48,6 @@ __all__ = ["fused_vocab_nll", "fused_loss_ready"]
 DEFAULT_BLOCK_T = 256
 DEFAULT_BLOCK_V = 512
 NEG_INF = -1e30
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 def _fit_block_v(vloc: int, block: int) -> int:
@@ -139,6 +137,7 @@ def _fwd_call(h, k, t2, block_t, block_v, interpret):
             pltpu.VMEM((block_t, 128), jnp.float32),
             pltpu.VMEM((block_t, 128), jnp.float32),
         ],
+        name="fused_vocab_nll_fwd",
         interpret=interpret,
     )(h, k, t2)
     return lse[:, 0], tgt[:, 0]
@@ -225,6 +224,7 @@ def _bwd_call(h, k, t2, lse1, g_lse, g_tgt, block_t, block_v, interpret):
         out_specs=[pl.BlockSpec((block_t, e), lambda it, iv: (it, 0))],
         out_shape=[jax.ShapeDtypeStruct((tpad, e), h.dtype)],
         scratch_shapes=[pltpu.VMEM((block_t, e), jnp.float32)],
+        name="fused_vocab_nll_bwd_dh",
         interpret=interpret,
     )(h, k, t2, lse2, gl2, gt2)
     dk, = pl.pallas_call(
@@ -238,6 +238,7 @@ def _bwd_call(h, k, t2, lse1, g_lse, g_tgt, block_t, block_v, interpret):
         out_specs=[pl.BlockSpec((e, block_v), lambda iv, it: (0, iv))],
         out_shape=[jax.ShapeDtypeStruct((e, vloc), k.dtype)],
         scratch_shapes=[pltpu.VMEM((e, block_v), jnp.float32)],
+        name="fused_vocab_nll_bwd_dk",
         interpret=interpret,
     )(h, k, t2, lse2, gl2, gt2)
     return dh, dk
@@ -292,8 +293,7 @@ def fused_vocab_nll(hidden, kernel, targets, *, axis_name: Optional[str] = None,
     cotangent, so gradients are exact); ``Vloc`` must satisfy
     :func:`fused_loss_ready` — callers fall back to the XLA path otherwise.
     """
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     vloc = kernel.shape[-1]
     if not fused_loss_ready(vloc):
         raise ValueError(f"fused loss needs a 128-multiple vocab shard, got "

@@ -41,13 +41,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .interpret import resolve_interpret
 from .quant import dequant_rows_tile
 
 NEG_INF = -1e30
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 def _kernel(bt_ref, kvl_ref, start_ref, chunk_ref,   # scalar prefetch
@@ -147,8 +144,7 @@ def paged_attention(q, k_pool, v_pool, block_table, start_pos, chunk_len,
     if Hq % Hk:
         raise ValueError(f"query heads {Hq} not a multiple of kv heads {Hk}")
     group = Hq // Hk
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(D)
 
@@ -200,6 +196,7 @@ def paged_attention(q, k_pool, v_pool, block_table, start_pos, chunk_len,
                           sm_scale=float(sm_scale), with_stats=return_stats),
         grid_spec=grid_spec,
         out_shape=out_shapes,
+        name="paged_attention",
         interpret=interpret,
     )(bt, kvl, start_pos.astype(jnp.int32), chunk_len.astype(jnp.int32),
       qt, k_pool, v_pool)
@@ -345,8 +342,7 @@ def paged_flash_decode(q, k_pool, v_pool, block_table, pos, kv_len, *,
     if not 0 <= layer < L:
         raise ValueError(f"layer {layer} outside the pool's {L} layers")
     group = Hq // Hk
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(D)
 
@@ -404,6 +400,7 @@ def paged_flash_decode(q, k_pool, v_pool, block_table, pos, kv_len, *,
                           quantized=quantized, with_stats=return_stats),
         grid_spec=grid_spec,
         out_shape=out_shapes,
+        name="paged_flash_decode",
         interpret=interpret,
     )(*args)
     if return_stats:

@@ -19,11 +19,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .interpret import resolve_interpret
+
 BLOCK = 2048  # elements per quantization block (16 (32,128)-lanes rows of int8)
-
-
-def _interp(interpret):
-    return jax.default_backend() == "cpu" if interpret is None else interpret
 
 
 TILE_BLOCKS = 16  # quant blocks per kernel invocation (16*2048 f32 = 128 KB)
@@ -57,10 +55,12 @@ def _dequant_kernel(q_ref, s_ref, x_ref):
 
 
 def _tile_rows(nb: int) -> int:
-    t = min(TILE_BLOCKS, nb)
-    while nb % t:
-        t -= 1
-    return t
+    """Row tile for an ``[nb, block]`` operand. The TPU lowering takes a row
+    tile that is a sublane multiple or the whole axis, so small inputs use
+    the whole axis and large ones ``TILE_BLOCKS`` with a ``pl.cdiv`` grid:
+    the ragged last tile reads padding and its out-of-range rows are
+    dropped on write, which is safe because every row is independent."""
+    return nb if nb <= TILE_BLOCKS else TILE_BLOCKS
 
 
 def shard_layout(n: int, world: int, block: int) -> Tuple[int, int, int]:
@@ -103,15 +103,15 @@ def quantize_int8(x: jnp.ndarray, block: int = BLOCK,
             raise ValueError("stochastic rounding needs a PRNG key")
         u = jax.random.uniform(key, (nb, block), jnp.float32)
         q, s = pl.pallas_call(
-            _quant_sr_kernel, grid=(nb // t,), in_specs=[spec, spec],
+            _quant_sr_kernel, grid=(pl.cdiv(nb, t),), in_specs=[spec, spec],
             out_specs=out_specs, out_shape=out_shape,
-            interpret=_interp(interpret),
+            interpret=resolve_interpret(interpret),
         )(x2, u)
     else:
         q, s = pl.pallas_call(
-            _quant_kernel, grid=(nb // t,), in_specs=[spec],
+            _quant_kernel, grid=(pl.cdiv(nb, t),), in_specs=[spec],
             out_specs=out_specs, out_shape=out_shape,
-            interpret=_interp(interpret),
+            interpret=resolve_interpret(interpret),
         )(x2)
     return q, s, shape
 
@@ -125,12 +125,12 @@ def dequantize_int8(q: jnp.ndarray, s: jnp.ndarray, shape, dtype=jnp.float32,
     t = _tile_rows(nb)
     x2 = pl.pallas_call(
         _dequant_kernel,
-        grid=(nb // t,),
+        grid=(pl.cdiv(nb, t),),
         in_specs=[pl.BlockSpec((t, block), lambda i: (i, 0), memory_space=pltpu.VMEM),
                   pl.BlockSpec((t, 128), lambda i: (i, 0), memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec((t, block), lambda i: (i, 0), memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
-        interpret=_interp(interpret),
+        interpret=resolve_interpret(interpret),
     )(q, s)
     return x2.reshape(-1)[:n].reshape(shape).astype(dtype)
 

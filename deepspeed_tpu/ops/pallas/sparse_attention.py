@@ -29,11 +29,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .interpret import resolve_interpret
+
 NEG_INF = -1e30
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 # ---------------------------------------------------------------------------
@@ -343,8 +341,7 @@ def sparse_attention(q, k, v, layout: np.ndarray, *, causal: bool = True,
     ``layout``: static numpy bool ``[H, S/block, S/block]`` (see the builders
     above). Only the layout's nonzero blocks are computed/DMA'd.
     """
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(q.shape[-1])
     b, sq, h, d = q.shape

@@ -19,8 +19,8 @@ STEP_GLOBAL_TIMER = "step"
 
 # one device sentinel, created on first use and reused: the previous
 # implementation issued a fresh jax.device_put H2D transfer on EVERY
-# stop(sync=True) — a per-step allocation + transfer on remote-attached
-# TPUs just to drain the dispatch queue. The chained +0 is what forces the
+# stop(sync=True) — a per-step allocation + transfer just to drain the
+# dispatch queue. The chained +0 is what forces the
 # queue to retire; the operand can be the same buffer every time.
 _SYNC_SENTINEL = None
 

@@ -28,13 +28,10 @@ def capture(log_dir: str, *, host_tracer_level: int = 2,
     """Context manager around any block of dispatches. The trace lands in
     ``<log_dir>/plugins/profile/<run>/`` (TensorBoard layout)."""
     os.makedirs(log_dir, exist_ok=True)
-    try:
-        options = jax.profiler.ProfileOptions()
-        options.host_tracer_level = host_tracer_level
-        options.python_tracer_level = python_tracer_level
-        jax.profiler.start_trace(log_dir, profiler_options=options)
-    except (AttributeError, TypeError):  # older jax: no ProfileOptions /
-        jax.profiler.start_trace(log_dir)  # no profiler_options kwarg
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = host_tracer_level
+    options.python_tracer_level = python_tracer_level
+    jax.profiler.start_trace(log_dir, profiler_options=options)
     try:
         yield log_dir
     finally:

@@ -123,8 +123,7 @@ class PrefetchLoader:
     sharding, leaves land directly in their dispatch layout so the engine's
     jit does no re-placement.
 
-    ``depth`` batches are kept in flight (2 = classic double buffering;
-    remote-attached TPUs with long H2D RTTs benefit from 3-4).
+    ``depth`` batches are kept in flight (2 = classic double buffering).
 
     Re-iterability and ``len()`` follow the WRAPPED loader: a list or
     ``DeepSpeedDataLoader`` gives a sized, re-iterable prefetcher; a one-shot

@@ -575,10 +575,17 @@ class DeepSpeedTPUEngine:
                              "pinned-host operands — optimizer state stays "
                              "device-resident (graceful degradation)")
 
-        ls = make_loss_scale_state(self.config.fp16.initial_scale_power,
+        # the scalars go onto the mesh like every other leaf: a step leaves
+        # them there (out_shardings), and a first call that saw them
+        # unplaced would be traced and compiled a second time for the types
+        # the second call brings
+        step, ls = jax.device_put(
+            (jnp.zeros([], jnp.int32),
+             make_loss_scale_state(self.config.fp16.initial_scale_power,
                                    self.config.fp16.loss_scale,
-                                   self.config.fp16.hysteresis)
-        self.state = TrainState(step=jnp.zeros([], jnp.int32), params=params,
+                                   self.config.fp16.hysteresis)),
+            topo.replicated())
+        self.state = TrainState(step=step, params=params,
                                 opt_state=opt_state, loss_scale=ls)
         self._opt_shardings = opt_sh
         self._param_shardings = param_sh
@@ -920,16 +927,24 @@ class DeepSpeedTPUEngine:
                                    is_leaf=lambda x: isinstance(x, P))
 
             def make_train_step(ltd_keep, moq_bits=None):
-                return jax.jit(partial(grad_step, ltd_keep=ltd_keep,
-                                       moq_bits=moq_bits),
+                step = partial(grad_step, ltd_keep=ltd_keep,
+                               moq_bits=moq_bits)
+                step.__name__ = "grad_step"  # see train_step below
+                return jax.jit(step,
                                in_shardings=(self._param_shardings, None, None, None),
                                out_shardings=(grad_sh, None))
         else:
             def make_train_step(ltd_keep, moq_bits=None):
                 # one compiled program per (random-LTD stage, MoQ bit-width)
                 # pair — both schedules quantize their steps, bounding the set
+                step = partial(train_step, ltd_keep=ltd_keep,
+                               moq_bits=moq_bits)
+                # a partial has no name and jit would call the program
+                # "<unknown>": profiler traces and IR dumps find the step
+                # by this one (jit_train_step)
+                step.__name__ = "train_step"
                 return jax.jit(
-                    partial(train_step, ltd_keep=ltd_keep, moq_bits=moq_bits),
+                    step,
                     in_shardings=(state_sh, None, None),
                     out_shardings=(state_sh, None),
                     donate_argnums=(0,) if donate_state else ())
@@ -1383,9 +1398,9 @@ class DeepSpeedTPUEngine:
             with span("compute/drain"):
                 jax.block_until_ready(metrics)  # sync-ok: opt-in windowed drain
         # Metrics stay on device; ``_last_metrics`` converts lazily. A per-step
-        # device->host sync here would serialize the async dispatch pipeline
-        # (one full RTT per step on remote-attached TPUs). Overflow-skip
-        # accounting is a device-side counter for the same reason.
+        # device->host sync here would serialize the async dispatch
+        # pipeline. Overflow-skip accounting is a device-side counter for
+        # the same reason.
         self._metrics_dev = metrics
         self._metrics_host = None
         if self.fp16:

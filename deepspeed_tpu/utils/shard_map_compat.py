@@ -1,73 +1,39 @@
-"""shard_map / axis-introspection version shims.
-
-jax >= 0.8 exposes ``jax.shard_map`` with a ``check_vma`` kwarg; older
-releases have ``jax.experimental.shard_map.shard_map`` with ``check_rep``.
-One probe, shared by every explicit-collective module (onebit, zeropp,
-tests) so the version logic cannot drift between copies. ``axis_size``
-shims ``lax.axis_size`` (jax >= 0.5) onto the classic ``psum(1, axis)``
-spelling the same way.
+"""The one place the package spells ``jax.shard_map`` and mesh-axis
+introspection (``analysis/lint.py`` R1 keeps raw ``shard_map`` calls out of
+every other module), so explicit-collective code — onebit, zeropp, the
+pipeline, tests — shares one calling convention.
 """
 
-import inspect
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_NOCHECK_KW = ({"check_vma": False}
-               if "check_vma" in inspect.signature(_shard_map).parameters
-               else {"check_rep": False})
+import jax
+from jax import lax
 
 
 def shard_map_nocheck(fn, mesh, in_specs, out_specs):
-    """shard_map with the replication/vma check disabled (whichever kwarg the
-    installed jax spells it with)."""
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **_NOCHECK_KW)
+    """shard_map with the varying-manual-axes check disabled."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def shard_map(fn, mesh, in_specs, out_specs, **kw):
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def axis_size(axis) -> int:
-    """Size of a mesh axis from inside shard_map — ``lax.axis_size`` where
-    it exists, else the trace-time-static ``psum(1, axis)`` spelling."""
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return int(lax.axis_size(axis))
-    return int(lax.psum(1, axis))
+    """Size of a mesh axis from inside shard_map (trace-time static)."""
+    return int(lax.axis_size(axis))
 
 
 def manual_axes() -> frozenset:
     """Mesh axes currently bound manual (i.e. tracing inside a shard_map).
     Callers that would NEST a shard_map (the collective-matmul overlap
-    wiring) must stay on the declarative path when this is non-empty.
-    New jax tracks it on the abstract mesh; old jax exposes the bound
-    axis env (private but stable across the 0.4.x line)."""
-    import jax
-
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        return frozenset(getattr(get_am(), "manual_axes", ()) or ())
-    try:
-        from jax._src.core import get_axis_env
-
-        return frozenset(get_axis_env().axis_sizes)
-    except Exception:  # pragma: no cover - future jax: fail open (no axes)
-        return frozenset()
+    wiring) must stay on the declarative path when this is non-empty."""
+    return frozenset(jax.sharding.get_abstract_mesh().manual_axes)
 
 
 def shard_map_nocheck_manual(fn, mesh, in_specs, out_specs, axis_names):
-    """``shard_map_nocheck`` with an explicit manual-axes set: new jax
-    spells it ``axis_names=<manual>``, old jax as the complement
-    ``auto=<all - manual>`` — translated here so callers write one form."""
-    kw = dict(_NOCHECK_KW)
-    if "check_vma" in _NOCHECK_KW:  # jax >= 0.8: native axis_names kwarg
-        kw["axis_names"] = set(axis_names)
-    else:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+    """``shard_map_nocheck`` over an explicit manual-axes set; the mesh's
+    other axes stay automatic."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False,
+                         axis_names=set(axis_names))

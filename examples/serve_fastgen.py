@@ -2,8 +2,8 @@
 
 Prompts of different lengths stream through SplitFuse-budgeted prefill
 chunks, then the whole decode run executes as one dispatch
-(``decode_stream``). On a real chip this path recorded 7.8k decode tok/s for
-a 12-layer 1536-hidden model (BENCH notes).
+(``decode_stream``). Decode tokens/s on a chip: not measured on the current
+code.
 """
 
 import os
